@@ -12,6 +12,7 @@ import json
 BENCH = """
 import time, json, sys, types
 import jax, jax.numpy as jnp
+from jax import set_mesh
 _m = types.ModuleType("benchmarks_schedule_model")
 def _schedule_time(costs, sizes, m, remat=True):
     # per-SAMPLE critical path (see unet_speed).
@@ -23,7 +24,6 @@ def _schedule_time(costs, sizes, m, remat=True):
     return (m + n - 1) / m * per_tick
 _m.schedule_time = _schedule_time
 sys.modules["benchmarks_schedule_model"] = _m
-from repro.compat import set_mesh
 from repro.configs.base import ParallelConfig
 from repro.launch import mesh as mesh_lib
 from repro.models.amoebanet import AmoebaConfig, AmoebaNetModel

@@ -90,8 +90,8 @@ OUT = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 BENCH = """
 import json, time
 import jax, jax.numpy as jnp, numpy as np
+from jax import set_mesh
 from repro import configs
-from repro.compat import set_mesh
 from repro.configs.base import ShapeConfig, ParallelConfig
 from repro.core import plan as plan_lib
 from repro.core import schedules as S

@@ -14,7 +14,7 @@ import json
 BENCH = """
 import json
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs.base import ParallelConfig
 from repro.launch import mesh as mesh_lib
 from repro.models.unet import UNetConfig, UNetModel

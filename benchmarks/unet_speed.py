@@ -11,6 +11,7 @@ import json
 BENCH = """
 import time, json, sys, types
 import jax, jax.numpy as jnp
+from jax import set_mesh
 _m = types.ModuleType("benchmarks_schedule_model")
 def _schedule_time(costs, sizes, m, remat=True):
     # per-SAMPLE critical path: ticks (m+n-1) x per-sample tick cost
@@ -26,7 +27,6 @@ def _sequential_time(costs, m):
 _m.schedule_time = _schedule_time
 _m.sequential_time = _sequential_time
 sys.modules["benchmarks_schedule_model"] = _m
-from repro.compat import set_mesh
 from repro.configs.base import ParallelConfig
 from repro.launch import mesh as mesh_lib
 from repro.models.unet import UNetConfig, UNetModel

@@ -14,18 +14,20 @@ if __name__ == "__main__" and "XLA_FLAGS" not in os.environ:
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro import configs
 from repro.configs.base import ParallelConfig, ShapeConfig
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch import mesh as mesh_lib, steps
+from repro.launch.cache import enable_compile_cache
 from repro.models.lm import LMModel
 from repro.optim import optimizers as optim
 from repro.planner import HardwareSpec
 
 
 def main():
+    enable_compile_cache()
     arch = configs.smoke_arch("smollm-360m")   # reduced dims, same family
     shape = ShapeConfig("train", seq_len=32, global_batch=8, kind="train")
     # one planner call replaces the manual five-knob dance (schedule,
@@ -47,7 +49,8 @@ def main():
 
     with set_mesh(mesh):
         train_step = jax.jit(
-            steps.build_train_step(model, pcfg, mesh, shape, ocfg))
+            steps.build_train_step(model, pcfg, mesh, shape, ocfg),
+            donate_argnums=(0, 1))
         for i in range(10):
             batch = {k: jnp.asarray(v) for k, v in data.batch_at(i).items()}
             params, opt, metrics = train_step(params, opt, batch)

@@ -11,16 +11,18 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 import numpy as np
 
-from repro.compat import set_mesh
 from repro import configs
 from repro.configs.base import ShapeConfig
 from repro.launch import mesh as mesh_lib, steps
+from repro.launch.cache import enable_compile_cache
 from repro.models.lm import LMModel
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b",
                     choices=configs.ARCH_NAMES)

@@ -15,13 +15,14 @@ import argparse
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro import configs
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs.base import ArchConfig, AttentionConfig, ParallelConfig, ShapeConfig
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch import mesh as mesh_lib, steps
+from repro.launch.cache import enable_compile_cache
 from repro.models.lm import LMModel
 from repro.optim import optimizers as optim
 from repro.planner import HardwareSpec
@@ -36,6 +37,7 @@ ARCH = ArchConfig(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--seq-len", type=int, default=128)

@@ -18,17 +18,19 @@ if __name__ == "__main__" and "XLA_FLAGS" not in os.environ:
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 import numpy as np
 
-from repro.compat import set_mesh
 from repro.configs.base import ParallelConfig
 from repro.launch import mesh as mesh_lib
+from repro.launch.cache import enable_compile_cache
 from repro.models import pipeline_hetero as PH
 from repro.models.unet import UNetConfig, UNetModel
 from repro.roofline import analysis as RA
 
 
 def main():
+    enable_compile_cache()
     cfg = UNetConfig(B=1, C=8, levels=4, img=64)
     x = jax.random.normal(jax.random.PRNGKey(1), (8, cfg.img, cfg.img, 3))
     outs = {}

@@ -19,7 +19,6 @@ from typing import Callable, List, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 
 def _feasible(costs: Sequence[float], n: int, cap: float) -> bool:
@@ -111,7 +110,7 @@ def balance_by_flops(layer_fns: Sequence[Callable], example_inputs, n: int) -> L
     costs = []
     for fn, x in zip(layer_fns, example_inputs):
         compiled = jax.jit(fn).lower(x).compile()
-        costs.append(float(compat.cost_analysis(compiled).get("flops", 0.0))
+        costs.append(float(compiled.cost_analysis().get("flops", 0.0))
                      or 1.0)
     return block_partition(costs, n)
 

@@ -57,7 +57,9 @@ Plan families select the backward story:
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -66,8 +68,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-from repro.compat import get_abstract_mesh, shard_map
 from repro.configs.base import ParallelConfig
 from repro.core import checkpointing
 from repro.core import plan as plan_lib
@@ -130,6 +130,29 @@ def _route_hop(value, perm, axis: str):
 
 BATCH_AXES = ("pod", "data")
 
+_HINTS = threading.local()
+
+
+@contextlib.contextmanager
+def no_layout_hints():
+    """Elide sharding constraints while tracing the body.
+
+    XLA requires every branch of a conditional to agree on its output
+    sharding.  A constraint that ends one branch, or that ends its transpose
+    (a constraint at the top of a branch becomes the last op of its
+    backward), breaks that against a branch returning plain zeros.
+    ``layers.constrain`` and :func:`_constrain_batch0` honour this flag."""
+    prev = getattr(_HINTS, "off", False)
+    _HINTS.off = True
+    try:
+        yield
+    finally:
+        _HINTS.off = prev
+
+
+def layout_hints_enabled() -> bool:
+    return not getattr(_HINTS, "off", False)
+
 
 def _constrain_batch0(tree, *, lead: int = 0):
     """Constrain pytree leaves: batch dim = ``lead`` over (pod, data).
@@ -139,10 +162,9 @@ def _constrain_batch0(tree, *, lead: int = 0):
     from jnp.zeros — without these constraints every carry is replicated
     over the data axis and per-device memory blows up by |data|x.
     """
-    if compat.skip_constraints():
-        return tree
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty or not set(BATCH_AXES) <= set(mesh.axis_names):
+    mesh = jax.sharding.get_abstract_mesh()
+    if (not layout_hints_enabled() or mesh.empty
+            or not set(BATCH_AXES) <= set(mesh.axis_names)):
         return tree
 
     nshard = 1
@@ -165,27 +187,12 @@ def _barrier(*trees):
     leaves = [l for f in flat for l in f]
     if not leaves:
         return trees
-    out = compat.optimization_barrier(tuple(leaves))
+    out = jax.lax.optimization_barrier(tuple(leaves))
     res, k = [], 0
     for f, td in zip(flat, tds):
         res.append(jax.tree_util.tree_unflatten(td, out[k:k + len(f)]))
         k += len(f)
     return tuple(res)
-
-
-def _oldjax_batch_axes(mesh, axis):
-    """Old-jax fully-manual fallback: the non-pipe mesh axes become explicit
-    batch parallelism.  Returns (axes, their size product)."""
-    baxes = tuple(a for a in mesh.axis_names if a != axis)
-    nd = 1
-    for a in baxes:
-        nd *= mesh.shape[a]
-    return baxes, nd
-
-
-def _oldjax_divisibility_error(nd):
-    return ValueError("jax 0.4.x fallback pipeline needs the micro-batch "
-                      f"divisible by pod*data*tp = {nd}")
 
 
 def _dyn_read(buf_tree, slot):
@@ -367,7 +374,6 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
                        carry_proto=None,
                        axis: str = PIPE_AXIS,
                        rank=None,
-                       loss_scale: float = 1.0,
                        resid_info: Optional[Dict[str, Any]] = None):
     """Execute one event plan (forward-only, or fused F+B) for a mini-batch.
 
@@ -489,7 +495,7 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
             raise ValueError(f"unknown grad_reduce {cfg.grad_reduce!r}; "
                              "want 'ordered' or 'running'")
         ordered = cfg.grad_reduce == "ordered"
-        seed = jnp.asarray(loss_scale / m, jnp.float32)
+        seed = jnp.asarray(1.0 / m, jnp.float32)
 
     if carry_proto is None:
         carry0 = jax.tree.map(lambda a: jnp.zeros(a.shape[1:], a.dtype),
@@ -606,10 +612,11 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
         carry_out, skips_out, res_new = stage_apply(p, c, si, resident_t, ctx)
         if not cfg.overlap:
             (carry_out,), = (_barrier(carry_out),)
-        loss_i = jax.lax.cond(
-            is_last_stage,
-            lambda: loss_fn(ph, carry_out, largs_t).astype(jnp.float32),
-            lambda: jnp.zeros((), jnp.float32))
+        with no_layout_hints():
+            loss_i = jax.lax.cond(
+                is_last_stage,
+                lambda: loss_fn(ph, carry_out, largs_t).astype(jnp.float32),
+                lambda: jnp.zeros((), jnp.float32))
         return carry_out, normalize_skips(skips_out), loss_i, res_new
 
     def make_full_f(micro_t, chunk_t, t, is_last_stage, resident_t, largs_t):
@@ -1067,8 +1074,18 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
                 branch_of = {NOP: nop_branch, FWD: f_branch, BWD: b_branch,
                              BWD_X: bx_branch, BWD_W: bw_branch}
                 branches = tuple(branch_of[k] for k in kinds_r)
-                res = (branches[0]() if len(branches) == 1
-                       else jax.lax.switch(sel_t, branches))
+                if len(branches) == 1 and kinds_r[0] in plan_lib.BWD_KINDS:
+                    # A lone backward task still runs as a conditional arm,
+                    # behind an index XLA cannot constant-fold: inlined, its
+                    # body fuses differently and its gradients change in the
+                    # last bits, breaking the bitwise equality of schedules
+                    # whose segments cut differently.
+                    res = jax.lax.switch(
+                        jax.lax.optimization_barrier(sel_t) + 1,
+                        (nop_branch,) + branches)
+                else:
+                    res = (branches[0]() if len(branches) == 1
+                           else jax.lax.switch(sel_t, branches))
             else:
                 ctx = TickCtx(stage=idx, micro=micro_t, valid=sel_t
                               == remap.get(FWD, -1), t=t, fresh=fresh_f,
@@ -1086,7 +1103,7 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
                         stage_params, x_f, skips_in, resident)
                     if not cfg.overlap:
                         (carry_out,), = (_barrier(carry_out),)
-                    return {"carry": _constrain_batch0(carry_out),
+                    return {"carry": carry_out,
                             "skips": normalize_skips(skips_out),
                             "res": res_new}
 
@@ -1094,6 +1111,9 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
                 branches = tuple(branch_of[k] for k in kinds_r)
                 res = (branches[0]() if len(branches) == 1
                        else jax.lax.switch(sel_t, branches))
+                # constrained after the switch: XLA requires every branch
+                # of a conditional to agree on its output sharding
+                res["carry"] = _constrain_batch0(res["carry"])
 
             # 4. commit state
             out = dict(st)
@@ -1484,42 +1504,22 @@ def pipeline_grad_call(stage_apply: StageApplyFn,
                               residuals=cfg.residuals,
                               wire=cfg.wire)
 
-    def inner(rank_arr, params, head_params, inputs_mb, loss_args_mb,
-              bdiv=1, psum_axes=()):
-        with compat.manual_region():
-            params = jax.tree.map(lambda a: a[0], params)
-            if streaming:
-                inputs_mb = jax.tree.map(lambda a: a[0], inputs_mb)
-
-            def localize(proto):
-                if proto is None or bdiv == 1:
-                    return proto
-                return jax.tree.map(
-                    lambda p: jax.ShapeDtypeStruct(
-                        (p.shape[0] // bdiv,) + tuple(p.shape[1:]), p.dtype),
-                    proto)
-
-            sk_protos = {kk: localize(val)
-                         for kk, val in (skip_protos or {}).items()}
-            loss_sum, g_stage, g_head, ig, _ = run_pipeline_tasks(
-                stage_apply, params, inputs_mb, cfg,
-                tplan=tplan, head_params=head_params,
-                loss_args_mb=loss_args_mb, loss_fn=loss_fn,
-                skip_protos=sk_protos,
-                carry_proto=localize(carry_proto), axis=axis,
-                rank=rank_arr[0], loss_scale=1.0 / bdiv,
-                resid_info=resid_info)
-            if psum_axes:
-                # batch axes are manual here (old-jax fallback): the DP
-                # gradient reduction is explicit.
-                loss_sum, g_stage, g_head = jax.lax.psum(
-                    (loss_sum, g_stage, g_head), psum_axes)
-            loss = loss_sum * (1.0 / (bdiv * m))
-            loss = loss[None]
-            g_stage = jax.tree.map(lambda a: a[None], g_stage)
-            g_head = jax.tree.map(lambda a: a[None], g_head)
-            ig = jax.tree.map(lambda a: a[None], ig)
-            return loss, g_stage, g_head, ig
+    def inner(rank_arr, params, head_params, inputs_mb, loss_args_mb):
+        params = jax.tree.map(lambda a: a[0], params)
+        if streaming:
+            inputs_mb = jax.tree.map(lambda a: a[0], inputs_mb)
+        loss_sum, g_stage, g_head, ig, _ = run_pipeline_tasks(
+            stage_apply, params, inputs_mb, cfg,
+            tplan=tplan, head_params=head_params,
+            loss_args_mb=loss_args_mb, loss_fn=loss_fn,
+            skip_protos=dict(skip_protos or {}),
+            carry_proto=carry_proto, axis=axis,
+            rank=rank_arr[0], resid_info=resid_info)
+        loss = (loss_sum * (1.0 / m))[None]
+        g_stage = jax.tree.map(lambda a: a[None], g_stage)
+        g_head = jax.tree.map(lambda a: a[None], g_head)
+        ig = jax.tree.map(lambda a: a[None], ig)
+        return loss, g_stage, g_head, ig
 
     def call(stage_params, head_params, inputs_mb, loss_args_mb):
         rank_arr = jnp.arange(n, dtype=jnp.int32)
@@ -1535,35 +1535,12 @@ def pipeline_grad_call(stage_apply: StageApplyFn,
                 lambda a: a.reshape((k, n) + a.shape[1:]).swapaxes(0, 1),
                 inputs_mb)
         if cfg.pipe > 1:
-            axis_names = {axis}
             in_spec_x = P(axis) if streaming else P()
-            in_spec_l = P()
-            out_spec_ig = P(axis)
-            bdiv, psum_axes = 1, ()
-            if not compat.JAX_HAS_NEW_API:
-                # Same old-jax fallback as pipeline_call: fully manual,
-                # non-pipe axes become explicit batch parallelism.
-                axis_names = set(mesh.axis_names)
-                baxes, nd = _oldjax_batch_axes(mesh, axis)
-                if nd > 1:
-                    bdim_in = 2 if streaming else 1
-                    leaves = jax.tree.leaves(inputs_mb)
-                    if not (all(l.ndim > bdim_in and l.shape[bdim_in] % nd == 0
-                                for l in leaves)
-                            and all(l.ndim > 1 and l.shape[1] % nd == 0
-                                    for l in jax.tree.leaves(loss_args_mb))):
-                        raise _oldjax_divisibility_error(nd)
-                    bdiv, psum_axes = nd, baxes
-                    in_spec_x = (P(axis, None, baxes) if streaming
-                                 else P(None, baxes))
-                    in_spec_l = P(None, baxes)
-                    out_spec_ig = P(axis, None, baxes)
-            fn = shard_map(
-                functools.partial(inner, bdiv=bdiv, psum_axes=psum_axes),
-                mesh=mesh,
-                in_specs=(P(axis), P(axis), P(), in_spec_x, in_spec_l),
-                out_specs=(P(axis), P(axis), P(axis), out_spec_ig),
-                axis_names=axis_names, check_vma=False)
+            fn = jax.shard_map(
+                inner, mesh=mesh,
+                in_specs=(P(axis), P(axis), P(), in_spec_x, P()),
+                out_specs=(P(axis), P(axis), P(axis), P(axis)),
+                axis_names={axis}, check_vma=False)
         else:
             fn = inner
         loss, g_stage, g_head, ig = fn(rank_arr, stage_params, head_params,
@@ -1618,35 +1595,21 @@ def pipeline_call(stage_apply: StageApplyFn,
     #    SHARDED over pipe (micro-batch i at rank i%n, slot i//n) and
     #    rotated one hop per plan tick; the transpose is a reverse rotation
     #    (no psum), memory drops by n, and bf16 is safe.
-    def inner(rank_arr, params, inputs_mb, resident, in_dtypes, cfg_run,
-              bdiv=1):
-        def localize(proto):
-            # protos describe GLOBAL batch shapes; inside a fully-manual
-            # region (old-jax fallback) each rank holds 1/bdiv of the batch.
-            if proto is None or bdiv == 1:
-                return proto
-            return jax.tree.map(
-                lambda p: jax.ShapeDtypeStruct(
-                    (p.shape[0] // bdiv,) + tuple(p.shape[1:]), p.dtype),
-                proto)
-
-        with compat.manual_region():
-            params = jax.tree.map(lambda a: a[0], params)
-            resident = jax.tree.map(lambda a: a[0], resident)
-            if cfg_run.stream_inputs:
-                inputs_mb = jax.tree.map(lambda a: a[0], inputs_mb)
-            inputs_mb = jax.tree.map(lambda a, d: a.astype(d), inputs_mb,
-                                     in_dtypes)
-            sk_protos = {k: localize(v)
-                         for k, v in (skip_protos or {}).items()}
-            outs, res = run_pipeline(stage_apply, params, inputs_mb, cfg_run,
-                                     skips=skips, skip_protos=sk_protos,
-                                     resident=resident,
-                                     carry_proto=localize(carry_proto),
-                                     axis=axis, rank=rank_arr[0])
-            outs = jax.tree.map(lambda a: a[None], outs)
-            res = jax.tree.map(lambda a: a[None], res)
-            return outs, res
+    def inner(rank_arr, params, inputs_mb, resident, in_dtypes, cfg_run):
+        params = jax.tree.map(lambda a: a[0], params)
+        resident = jax.tree.map(lambda a: a[0], resident)
+        if cfg_run.stream_inputs:
+            inputs_mb = jax.tree.map(lambda a: a[0], inputs_mb)
+        inputs_mb = jax.tree.map(lambda a, d: a.astype(d), inputs_mb,
+                                 in_dtypes)
+        outs, res = run_pipeline(stage_apply, params, inputs_mb, cfg_run,
+                                 skips=skips,
+                                 skip_protos=dict(skip_protos or {}),
+                                 resident=resident, carry_proto=carry_proto,
+                                 axis=axis, rank=rank_arr[0])
+        outs = jax.tree.map(lambda a: a[None], outs)
+        res = jax.tree.map(lambda a: a[None], res)
+        return outs, res
 
     def call(stage_params, inputs_mb, resident=None):
         resident = {} if resident is None else resident
@@ -1668,51 +1631,12 @@ def pipeline_call(stage_apply: StageApplyFn,
                 if a.dtype == jnp.bfloat16 else a, inputs_mb)
         rank_arr = jnp.arange(n, dtype=jnp.int32)
         if cfg.pipe > 1:
-            axis_names = {axis}
-            in_spec_res = out_spec_res = P(axis)
-            out_spec_outs = P(axis)
-            bdiv = 1
-            if not compat.JAX_HAS_NEW_API:
-                # jax 0.4.x: the partial-auto partitioner aborts on this
-                # program shape (XLA IsManualSubgroup check), so go FULLY
-                # manual and express what GSPMD would have derived by hand:
-                # every non-pipe axis becomes batch parallelism.  The
-                # tensor-parallel constraints inside the stage are already
-                # elided (compat.skip_constraints), so treating ``tp`` as
-                # extra DP is exact — each rank computes a distinct batch
-                # slice and the shard_map transpose psums parameter
-                # cotangents over the non-pipe axes (the DP grad reduction).
-                axis_names = set(mesh.axis_names)
-                baxes, nd = _oldjax_batch_axes(mesh, axis)
-                bdim_in = 2 if streaming else 1
-                if nd > 1:
-                    def divisible(leaf, d):
-                        return leaf.ndim > d and leaf.shape[d] % nd == 0
-                    if not (all(divisible(l, bdim_in)
-                                for l in jax.tree.leaves(up))
-                            and all(l.ndim < 4 or divisible(l, 3)
-                                    for l in jax.tree.leaves(resident))):
-                        raise _oldjax_divisibility_error(nd)
-                    bdiv = nd
-                    if streaming:
-                        in_spec_x = P(axis, None, baxes)
-                    else:
-                        in_spec_x = P(None, baxes)
-                    # resident caches: [n, L, m, mb, ...] -> batch at dim 3;
-                    # low-rank leaves (per-micro trackers) are replicated.
-                    def res_spec(leaf):
-                        if leaf.ndim >= 4:
-                            return P(axis, None, None, baxes)
-                        return P(axis)
-                    in_spec_res = jax.tree.map(res_spec, resident)
-                    out_spec_res = in_spec_res
-                    out_spec_outs = P(axis, None, baxes)
-            fn = shard_map(
+            fn = jax.shard_map(
                 functools.partial(inner, in_dtypes=in_dtypes,
-                                  cfg_run=cfg_run, bdiv=bdiv), mesh=mesh,
-                in_specs=(P(axis), P(axis), in_spec_x, in_spec_res),
-                out_specs=(out_spec_outs, out_spec_res),
-                axis_names=axis_names, check_vma=False)
+                                  cfg_run=cfg_run), mesh=mesh,
+                in_specs=(P(axis), P(axis), in_spec_x, P(axis)),
+                out_specs=(P(axis), P(axis)),
+                axis_names={axis}, check_vma=False)
         else:
             # Degenerate single-stage pipeline: plain sequential execution,
             # no manual axis (avoids size-1 manual subgroups).

@@ -1,12 +1,15 @@
 """jit'd dispatch wrappers around the Pallas kernels.
 
 Dispatch policy:
-  * TPU backend           -> Pallas kernels (compiled).
-  * REPRO_PALLAS_INTERPRET=1 -> Pallas kernels in interpret mode (CPU tests).
+  * TPU backend -> Pallas kernels (compiled).
   * otherwise (CPU dry-run / smokes) -> the blocked pure-jnp implementations
     from :mod:`repro.kernels.ref`, which share the kernels' algorithmic
     structure (no [S, S] materialization) so the dry-run roofline reflects
     the same memory behaviour the TPU kernel has.
+
+Tests that force the Pallas path off the TPU (monkeypatching
+:func:`_use_pallas`) get the kernels in interpret mode; a TPU backend never
+interprets.
 
 The Pallas forwards are wrapped in ``jax.custom_vjp`` with backward passes
 taken from the reference implementations' VJPs: the recurrences are linear
@@ -18,14 +21,14 @@ EXPERIMENTS.md §Perf.)
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.rwkv6 import wkv6_pallas
 
 
@@ -34,7 +37,58 @@ def _use_pallas() -> bool:
 
 
 def _interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "") == "1"
+    return jax.default_backend() != "tpu"
+
+
+BATCH_AXES = ("pod", "data")
+HEAD_AXIS = "tp"
+
+
+def _per_device(fn, args, dims, out_dims):
+    """Call a Pallas kernel on each device's shard.
+
+    Mosaic kernels cannot be partitioned by the compiler, so under a mesh
+    with automatic axes the call runs inside a ``shard_map``.  It names
+    every mesh axis, the ones an enclosing ``shard_map`` (the pipeline's,
+    over ``pipe``) already made manual too: the TPU lowering refuses a
+    kernel whose context leaves any axis automatic.  ``dims`` (per
+    argument) and ``out_dims`` (per output) name the ``(batch, heads)``
+    dims, or ``None``: batch shards over (pod, data) and heads over tp
+    where the sizes divide; everything else is replicated.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.auto_axes:
+        return fn(*args)
+    auto = set(mesh.auto_axes)
+    baxes = tuple(a for a in BATCH_AXES if a in auto)
+    nb = 1
+    for a in baxes:
+        nb *= mesh.shape[a]
+    nh = mesh.shape[HEAD_AXIS] if HEAD_AXIS in auto else 1
+    bdims = [(a.shape[d[0]] if d and d[0] is not None else 0,
+              a.shape[d[1]] if d and d[1] is not None else 0)
+             for a, d in zip(args, dims)]
+    shard_b = bool(baxes) and all(b % nb == 0 for b, _ in bdims if b)
+    shard_h = nh > 1 and all(h % nh == 0 for _, h in bdims if h)
+
+    def spec(ndim, d):
+        out = [None] * ndim
+        if d is not None:
+            if d[0] is not None and shard_b:
+                out[d[0]] = baxes
+            if d[1] is not None and shard_h:
+                out[d[1]] = HEAD_AXIS
+        return P(*out)
+
+    in_specs = tuple(spec(a.ndim, d) for a, d in zip(args, dims))
+    out_proto = jax.eval_shape(fn, *args)
+    if isinstance(out_proto, (tuple, list)):
+        out_specs = type(out_proto)(spec(o.ndim, d)
+                                    for o, d in zip(out_proto, out_dims))
+    else:
+        out_specs = spec(out_proto.ndim, out_dims)
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=set(mesh.axis_names), check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -43,8 +97,9 @@ def _interpret() -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _attention_pallas(q, k, v, causal, window, q_offset):
-    return flash_attention(q, k, v, causal=causal, window=window,
+    fn = functools.partial(flash_attention, causal=causal, window=window,
                            q_offset=q_offset, interpret=_interpret())
+    return _per_device(fn, (q, k, v), [(0, 1)] * 3, (0, 1))
 
 
 def _attention_fwd(q, k, v, causal, window, q_offset):
@@ -75,7 +130,7 @@ def attention(q, k, v, *, causal=True, window=None, q_offset: int = 0,
     static = (isinstance(causal, (bool, int))
               and (window is None or isinstance(window, int))
               and kv_len is None)
-    if (_use_pallas() or _interpret()) and static:
+    if _use_pallas() and static:
         return _attention_pallas(q, k, v, bool(causal), int(window or 0),
                                  q_offset)
     return ref.mha_blocked(q, k, v, causal=causal, window=window,
@@ -88,7 +143,9 @@ def attention(q, k, v, *, causal=True, window=None, q_offset: int = 0,
 
 @functools.partial(jax.custom_vjp)
 def _wkv6_pallas_op(r, k, v, w, u, s0):
-    return wkv6_pallas(r, k, v, w, u, s0, interpret=_interpret())
+    fn = functools.partial(wkv6_pallas, interpret=_interpret())
+    return _per_device(fn, (r, k, v, w, u, s0),
+                       [(0, 1)] * 4 + [(None, 0), (0, 1)], [(0, 1), (0, 1)])
 
 
 def _wkv6_fwd(r, k, v, w, u, s0):
@@ -110,7 +167,7 @@ def wkv6(r, k, v, w, u, state0=None):
     if state0 is None:
         B, H, _, K = r.shape
         state0 = jnp.zeros((B, H, K, v.shape[-1]), jnp.float32)
-    if _use_pallas() or _interpret():
+    if _use_pallas():
         return _wkv6_pallas_op(r, k, v, w, u, state0)
     return ref.wkv6(r, k, v, w, u, state0)
 
@@ -119,10 +176,28 @@ def wkv6(r, k, v, w, u, state0=None):
 # RMSNorm
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rmsnorm_pallas_op(x, scale, eps):
+    fn = functools.partial(rmsnorm_pallas, eps=eps, interpret=_interpret())
+    return _per_device(fn, (x, scale), [(0, None), None], (0, None))
+
+
+def _rmsnorm_fwd(x, scale, eps):
+    return _rmsnorm_pallas_op(x, scale, eps), (x, scale)
+
+
+def _rmsnorm_bwd(eps, res, g):
+    x, scale = res
+    _, vjp = jax.vjp(lambda x_, s_: ref.rmsnorm(x_, s_, eps), x, scale)
+    return vjp(g)
+
+
+_rmsnorm_pallas_op.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
+
+
 def rmsnorm(x, scale, eps: float = 1e-6):
-    if _use_pallas() or _interpret():
-        from repro.kernels.rmsnorm import rmsnorm_pallas
-        return rmsnorm_pallas(x, scale, eps, interpret=_interpret())
+    if _use_pallas():
+        return _rmsnorm_pallas_op(x, scale, eps)
     return ref.rmsnorm(x, scale, eps)
 
 

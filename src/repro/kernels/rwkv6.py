@@ -27,9 +27,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 
-_CompilerParams = pallas_compiler_params()
+def _dot(a, b, ca: int, cb: int):
+    """fp32 matmul contracting ``a``'s dim ``ca`` with ``b``'s dim ``cb``."""
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sT_ref,
@@ -44,33 +47,38 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sT_ref,
     k = k_ref[0].astype(jnp.float32)          # [C, K]
     v = v_ref[0].astype(jnp.float32)          # [C, V]
     w = w_ref[0].astype(jnp.float32)          # [C, K]
-    u = u_ref[0].astype(jnp.float32)          # [K]
+    u = u_ref[0].astype(jnp.float32)          # [1, K]
 
+    C = chunk
+    K = r.shape[1]
+    ti = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
     logw = jnp.log(jnp.maximum(w, 1e-30))
-    cum = jnp.cumsum(logw, axis=0)            # [C, K] inclusive
+    # inclusive prefix sum over time as a lower-triangular matmul (Mosaic
+    # has no cumsum), at full fp32 precision
+    cum = _dot(jnp.where(si <= ti, 1.0, 0.0), logw, 1, 0)   # [C, K]
     qdecay = jnp.exp(cum - logw)              # exp(cum_{t-1}) (exclusive)
     kdecay_in = jnp.exp(-cum)                 # exp(-cum_i)
-    total = cum[-1]                           # [K]
+    total = cum[C - 1:C, :]                   # [1, K]
 
     s_in = state[...]                         # [K, V]
     # inter-chunk term
-    inter = jax.lax.dot_general(r * qdecay, s_in, (((1,), (0,)), ((), ())))
+    inter = _dot(r * qdecay, s_in, 1, 0)
     # intra-chunk: att[t, i] = sum_k r_t q decay / k decay — computed as
     # (r*qdecay) @ (k*kdecay_in)^T, valid for i < t (strict lower triangle).
-    att = jax.lax.dot_general(r * qdecay, k * kdecay_in,
-                              (((1,), (1,)), ((), ())))    # [C, C]
-    C = chunk
-    ti = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    att = _dot(r * qdecay, k * kdecay_in, 1, 1)              # [C, C]
     att = jnp.where(si < ti, att, 0.0)
-    intra = jax.lax.dot_general(att, v, (((1,), (0,)), ((), ())))
-    bonus = jnp.sum(r * k * u[None, :], axis=1, keepdims=True) * v
+    intra = _dot(att, v, 1, 0)
+    bonus = jnp.sum(r * k * u, axis=1, keepdims=True) * v
     o_ref[0] = (inter + intra + bonus).astype(o_ref.dtype)
 
-    # state update
-    kout = k * jnp.exp(total[None, :] - cum)  # exp(cum_C - cum_i) k_i
-    state[...] = jnp.exp(total)[:, None] * s_in + jax.lax.dot_general(
-        kout, v, (((0,), (0,)), ((), ())))
+    # state update; the per-row decay exp(total_k) of the [K, V] state is a
+    # diagonal matmul, which keeps the [1, K] row vector in lanes
+    kout = k * jnp.exp(total - cum)           # exp(cum_C - cum_i) k_i
+    ki = jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
+    kj = jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)
+    decay = jnp.where(ki == kj, jnp.exp(total), 0.0)         # diag, [K, K]
+    state[...] = _dot(decay, s_in, 1, 0) + _dot(kout, v, 0, 0)
 
     @pl.when(ci == nc - 1)
     def _fin():
@@ -96,7 +104,9 @@ def wkv6_pallas(r, k, v, w, u, state0=None, *, chunk: int = 64,
     kf = k.reshape(BH, T, K)
     vf = v.reshape(BH, T, V)
     wf = w.reshape(BH, T, K)
-    uf = jnp.broadcast_to(u[None], (B, H, K)).reshape(BH, K)
+    # [H, 1, K]: a (1, K) block then spans the array's last two dims, as
+    # the TPU tiling requires of blocks narrower than (8, 128)
+    uf = u.reshape(H, 1, K)
     s0 = (jnp.zeros((BH, K, V), jnp.float32) if state0 is None
           else state0.reshape(BH, K, V).astype(jnp.float32))
 
@@ -109,7 +119,7 @@ def wkv6_pallas(r, k, v, w, u, state0=None, *, chunk: int = 64,
             pl.BlockSpec((1, C, K), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, C, V), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, C, K), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, K), lambda bh, ci: (bh, 0)),
+            pl.BlockSpec((1, 1, K), lambda bh, ci: (bh % H, 0, 0)),
             pl.BlockSpec((1, K, V), lambda bh, ci: (bh, 0, 0)),
         ],
         out_specs=[
@@ -121,7 +131,7 @@ def wkv6_pallas(r, k, v, w, u, state0=None, *, chunk: int = 64,
             jax.ShapeDtypeStruct((BH, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, uf, s0)

@@ -22,10 +22,9 @@ import time
 import traceback
 
 import jax
+from jax import set_mesh
 import numpy as np
 
-from repro import compat
-from repro.compat import set_mesh
 from repro import configs
 from repro.configs.base import SHAPES_BY_NAME, V5E
 from repro.core import plan as plan_lib
@@ -89,7 +88,7 @@ def run_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
         compiled = lowered.compile()
         t2 = time.time()
     mem = compiled.memory_analysis()
-    ca = compat.cost_analysis(compiled) or {}
+    ca = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     cost = analysis.analyze_hlo(hlo, n_dev)
     mf = analysis.model_flops_for(arch, shape) / n_dev

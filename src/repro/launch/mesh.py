@@ -1,4 +1,5 @@
-"""Production mesh construction (assignment spec) + derived arch meshes.
+"""Mesh construction: the local mesh every entry point runs on, and the
+production grid (assignment spec) that ``launch/dryrun.py`` compiles for.
 
 ``make_production_mesh`` is exactly the assignment's canonical grid:
 ``(data=16, model=16)`` per pod, ``(pod=2, data=16, model=16)`` multi-pod.
@@ -13,16 +14,11 @@ inside functions only.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-# AxisType only exists on jax >= 0.5; repro.compat supplies a no-op enum (and
-# axis_types-tolerant constructors) on 0.4.x so collection never breaks.
-from repro.compat import AxisType, make_mesh, mesh_with_axis_types
 from repro.configs.base import ParallelConfig
 
 
@@ -30,20 +26,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(axes),
-                     devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:n])
 
 
-def make_arch_mesh(pcfg: ParallelConfig, *, base: Optional[Mesh] = None) -> Mesh:
-    """Refine the production mesh's ``model`` axis into ``pipe × tp``.
+def make_arch_mesh(pcfg: ParallelConfig, *, base: Mesh) -> Mesh:
+    """Refine the production mesh ``base``'s ``model`` axis into
+    ``pipe × tp``.
 
     Returns a 4-axis mesh ``(pod, data, pipe, tp)`` over the identical device
-    grid (pod=1 single-pod).  Falls back to whatever devices exist when the
-    full 256/512 grid is unavailable (smoke tests pass pipe/tp/data of 1).
+    grid (pod=1 single-pod).
     """
-    if base is None:
-        base = make_production_mesh(multi_pod=pcfg.pod > 1)
     devs = np.asarray(base.devices)
     if devs.ndim == 2:
         devs = devs[None]                       # (pod=1, data, model)
@@ -58,8 +52,8 @@ def make_arch_mesh(pcfg: ParallelConfig, *, base: Optional[Mesh] = None) -> Mesh
     # assignment's canonical (data, model) grid intact.
     grid = devs.reshape(pod, data, pcfg.dp2, pcfg.pipe, pcfg.tp) \
         .reshape(pod, data * pcfg.dp2, pcfg.pipe, pcfg.tp)
-    return mesh_with_axis_types(grid, ("pod", "data", "pipe", "tp"),
-                                axis_types=(AxisType.Auto,) * 4)
+    return Mesh(grid, ("pod", "data", "pipe", "tp"),
+                axis_types=(AxisType.Auto,) * 4)
 
 
 # The chain-collective topology lives next to the plan IR (one definition
@@ -68,10 +62,25 @@ def make_arch_mesh(pcfg: ParallelConfig, *, base: Optional[Mesh] = None) -> Mesh
 from repro.core.plan import pipe_ring_perm  # noqa: E402,F401
 
 
+def fit_local(pcfg: ParallelConfig, *, pipe: int = 0,
+              data: int = 0) -> ParallelConfig:
+    """``pcfg`` laid out over the devices present: one pod, no tensor
+    parallelism, ``data`` replicas (default 1) of a ``pipe``-stage pipeline
+    (default: every remaining device)."""
+    n = len(jax.devices())
+    data = data or 1
+    pipe = pipe or max(1, n // data)
+    if pipe * data > n:
+        raise ValueError(f"pipe={pipe} x data={data} needs {pipe * data} "
+                         f"devices, {n} present")
+    return pcfg.with_(pod=1, tp=1, dp2=1, data=data, pipe=pipe)
+
+
 def make_smoke_mesh(pcfg: ParallelConfig) -> Mesh:
-    """Mesh over however many local devices the reduced configs use."""
+    """Mesh over the first ``pod * data * pipe * tp`` local devices: the
+    reduced configs, and the entry points' runs on the devices present."""
     n = pcfg.pod * pcfg.data * pcfg.pipe * pcfg.tp
     devs = np.array(jax.devices()[:n]).reshape(
         pcfg.pod, pcfg.data, pcfg.pipe, pcfg.tp)
-    return mesh_with_axis_types(devs, ("pod", "data", "pipe", "tp"),
-                                axis_types=(AxisType.Auto,) * 4)
+    return Mesh(devs, ("pod", "data", "pipe", "tp"),
+                axis_types=(AxisType.Auto,) * 4)

@@ -13,12 +13,13 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 import numpy as np
 
-from repro.compat import set_mesh
 from repro import configs
 from repro.configs.base import ShapeConfig
 from repro.launch import mesh as mesh_lib, steps
+from repro.launch.cache import enable_compile_cache
 from repro.models.lm import LMModel
 
 
@@ -31,18 +32,25 @@ def main():
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--temperature", type=float, default=0.0)
+    ap.add_argument("--pipe", type=int, default=0,
+                    help="pipe degree (default: 1 with --smoke, else every "
+                         "device)")
+    ap.add_argument("--data", type=int, default=0,
+                    help="data-parallel degree (default 1)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         arch = configs.smoke_arch(args.arch)
-        pcfg = configs.smoke_parallel(args.arch)
-        mesh = mesh_lib.make_smoke_mesh(pcfg)
+        pcfg = configs.smoke_parallel(args.arch).with_(
+            pipe=args.pipe or 1, data=args.data or 1)
         dtype = jnp.float32
     else:
         arch = configs.get_arch(args.arch)
-        pcfg = configs.get_parallel(args.arch)
-        mesh = mesh_lib.make_arch_mesh(pcfg)
+        pcfg = mesh_lib.fit_local(configs.get_parallel(args.arch),
+                                  pipe=args.pipe, data=args.data)
         dtype = jnp.bfloat16
+    mesh = mesh_lib.make_smoke_mesh(pcfg)
 
     max_len = args.prompt_len + args.gen
     pshape = ShapeConfig("prefill", args.prompt_len, args.batch, "prefill")

@@ -7,9 +7,9 @@ real mesh):
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \\
         --smoke --steps 50 --ckpt-dir /tmp/ckpt
 
-``--smoke`` uses the reduced arch + 1-device mesh; otherwise the full
-assigned config and the arch's production pipe x tp layout are used
-(requires the matching device pool).
+``--smoke`` uses the reduced arch on one device; otherwise the full
+assigned config in bf16, pipelined over every device present.  Either way
+``--pipe``/``--data`` set the layout over the devices present.
 
 Fault-tolerance demo knobs: ``--fail-at`` injects plain preemptions,
 ``--shrink-at step:pool`` kills a slice for good (the supervisor re-plans
@@ -23,14 +23,15 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 import numpy as np
 
-from repro.compat import set_mesh
 from repro import configs
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs.base import ParallelConfig, ShapeConfig
 from repro.data.pipeline import DataConfig, SyntheticLM
-from repro.launch import mesh as mesh_lib, steps
+from repro.launch import mesh as mesh_lib, sharding, steps
+from repro.launch.cache import enable_compile_cache
 from repro.models.lm import LMModel
 from repro.optim import optimizers as optim
 from repro.planner import search as planner_search
@@ -61,7 +62,6 @@ class ElasticTrainer:
                  dtype=jnp.float32,
                  hardware: Optional[HardwareSpec] = None,
                  executors: Tuple[str, ...] = ("spmd",),
-                 mesh_fn=mesh_lib.make_smoke_mesh,
                  injector: Optional[FaultInjector] = None,
                  verbose: bool = False):
         self.arch = arch
@@ -70,7 +70,6 @@ class ElasticTrainer:
         self.dtype = dtype
         self.hw = hardware or HardwareSpec(ranks=max(pcfg.pipe, 1))
         self.executors = tuple(executors)
-        self.mesh_fn = mesh_fn
         self.injector = injector
         self.verbose = verbose
         self.data = data or SyntheticLM(
@@ -84,10 +83,13 @@ class ElasticTrainer:
     def _setup(self, pcfg: ParallelConfig):
         self.pcfg = pcfg
         self.model = LMModel(self.arch, pcfg, dtype=self.dtype)
-        self.mesh = self.mesh_fn(pcfg)
+        self.mesh = mesh_lib.make_smoke_mesh(pcfg)
         with set_mesh(self.mesh):
-            self._step_jit = jax.jit(steps.build_train_step(
-                self.model, pcfg, self.mesh, self.shape, self.ocfg))
+            # params and optimizer state are donated: at full width they do
+            # not fit twice.  Checkpoints copy to host before the next step.
+            self.jit_step = jax.jit(steps.build_train_step(
+                self.model, pcfg, self.mesh, self.shape, self.ocfg),
+                donate_argnums=(0, 1))
 
     def plan_layout(self, pool: int) -> ParallelConfig:
         """The layout a pool of ``pool`` devices gets: ``choose_layout``
@@ -116,13 +118,19 @@ class ElasticTrainer:
 
     # ------------------------------------------------------- state hooks
     def make_state(self, restored):
-        if restored is not None:
-            return restored
-        params = self.model.init(jax.random.PRNGKey(0))
-        return {"params": params,
-                "opt": optim.init(
-                    self.ocfg, params,
-                    with_ef=self.pcfg.grad_compression == "int8_ef")}
+        """Fresh (or restored) state, placed on the mesh: each stage's
+        parameters and moments on its own pipe rank."""
+        state = restored
+        if state is None:
+            params = self.model.init(jax.random.PRNGKey(0))
+            state = {"params": params,
+                     "opt": optim.init(
+                         self.ocfg, params,
+                         with_ef=self.pcfg.grad_compression == "int8_ef")}
+        pspecs = sharding.param_specs(state["params"], self.mesh)
+        specs = {"params": pspecs,
+                 "opt": sharding.opt_state_specs(pspecs, state["opt"])}
+        return jax.device_put(state, sharding.named(specs, self.mesh))
 
     def adapt_state(self, tree, extra):
         """Verify fingerprint + restack a restored checkpoint onto the
@@ -145,8 +153,9 @@ class ElasticTrainer:
         if self.injector is not None:
             batch = self.injector.maybe_poison(i, batch)
         with set_mesh(self.mesh):
-            p, o, m = self._step_jit(state["params"], state["opt"], batch)
-        metrics = {"loss": float(m["loss"])}
+            p, o, m = self.jit_step(state["params"], state["opt"], batch)
+        metrics = {"loss": float(m["loss"]),
+                   "grad_norm": float(m["grad_norm"])}
         if "skipped" in m:
             metrics["skipped"] = int(m["skipped"])
             metrics["finite"] = float(m["finite"])
@@ -206,10 +215,11 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--pipe", type=int, default=0,
-                    help="override pipe degree (smoke demos; needs the "
-                         "matching --xla_force_host_platform_device_count)")
+                    help="pipe degree (default: 1 with --smoke, else every "
+                         "device; on CPU, devices come from "
+                         "--xla_force_host_platform_device_count)")
     ap.add_argument("--data", type=int, default=0,
-                    help="override data-parallel degree (smoke demos)")
+                    help="data-parallel degree (default 1)")
     ap.add_argument("--fail-at", type=int, nargs="*", default=[],
                     help="inject preemptions at these steps (demo/testing)")
     ap.add_argument("--shrink-at", nargs="*", default=[], metavar="STEP:POOL",
@@ -220,22 +230,18 @@ def main():
                          "demo; needs a float-input arch, e.g. "
                          "whisper-tiny)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         arch = configs.smoke_arch(args.arch)
-        pcfg = configs.smoke_parallel(args.arch)
+        pcfg = configs.smoke_parallel(args.arch).with_(
+            pipe=args.pipe or 1, data=args.data or 1)
         dtype = jnp.float32
-        mesh_fn = mesh_lib.make_smoke_mesh
     else:
         arch = configs.get_arch(args.arch)
-        pcfg = configs.get_parallel(args.arch)
+        pcfg = mesh_lib.fit_local(configs.get_parallel(args.arch),
+                                  pipe=args.pipe, data=args.data)
         dtype = jnp.bfloat16
-        mesh_fn = mesh_lib.make_arch_mesh
-
-    if args.pipe:
-        pcfg = pcfg.with_(pipe=args.pipe)
-    if args.data:
-        pcfg = pcfg.with_(data=args.data)
 
     shape = ShapeConfig("train", args.seq_len, args.batch, "train")
     pcfg = pcfg.with_(n_micro=configs.derive_n_micro(shape, pcfg))
@@ -246,8 +252,7 @@ def main():
                              shrink_at=_parse_shrink(args.shrink_at),
                              poison_at_steps=tuple(args.poison_at))
     trainer = ElasticTrainer(arch, pcfg, shape, ocfg, dtype=dtype,
-                             mesh_fn=mesh_fn, injector=injector,
-                             verbose=True)
+                             injector=injector, verbose=True)
     print(f"[train] {arch.name}: {arch.total_params()/1e6:.1f}M params, "
           f"pipe={pcfg.pipe} tp={pcfg.tp} m={pcfg.n_micro} "
           f"mesh={dict(trainer.mesh.shape)}")

@@ -19,9 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-from repro.compat import get_abstract_mesh
 from repro.configs.base import ArchConfig, AttentionConfig, MoEConfig, SSMConfig
+from repro.core.pipeline import layout_hints_enabled
 from repro.kernels import ops
 
 BATCH = ("pod", "data")
@@ -30,10 +29,8 @@ TP = "tp"
 
 def constrain(x, spec: P):
     """with_sharding_constraint iff the current mesh has the spec's axes."""
-    if compat.skip_constraints():
-        return x
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not layout_hints_enabled() or mesh.empty:
         return x
     names = set(mesh.axis_names)
     def ok(entry):

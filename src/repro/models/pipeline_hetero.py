@@ -130,9 +130,8 @@ def build_hetero_program(model, params, mb: int, pcfg: ParallelConfig,
             outs = {}
             for li in range(bounds[s], bounds[s + 1]):
                 x = model.layer_apply(li, p_list[li - bounds[s]], x, store)
-            # zero skips take the RUNTIME batch (x.shape[0]): inside the
-            # old-jax fully-manual region the local batch is 1/bdiv of the
-            # proto's global batch, and switch branches must agree.
+            # zero skips take the runtime batch (x.shape[0]): switch
+            # branches must agree on their output shapes.
             skips_out = {e.name: (store[e.name] if e.name in store
                                   else jnp.zeros(
                                       (x.shape[0],)

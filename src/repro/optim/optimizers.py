@@ -86,7 +86,10 @@ def init(cfg: OptimizerConfig, params, *, with_ef: bool = False) -> OptState:
     gradient compression (ParallelConfig.grad_compression="int8_ef"); it
     mirrors the params leaf-for-leaf so it shards like the moments."""
     f32 = lambda p: jnp.zeros(p.shape, jnp.float32)
-    master = jax.tree.map(lambda p: p.astype(jnp.float32), params)
+    # a copy even where params are fp32 already: the step donates params
+    # and optimizer state, which must not share a buffer
+    master = jax.tree.map(lambda p: jnp.array(p, jnp.float32, copy=True),
+                          params)
     scale0 = cfg.init_loss_scale if cfg.dynamic_loss_scale else 1.0
     return OptState(step=jnp.zeros((), jnp.int32),
                     mu=jax.tree.map(f32, params),

@@ -457,7 +457,7 @@ def test_whisper_poisoned_batch_skips_and_converges():
 import numpy as np
 import jax, jax.numpy as jnp
 from repro import configs
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch import mesh as mesh_lib, steps

@@ -6,7 +6,7 @@ from conftest import run_subprocess
 
 UNET = """
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs.base import ParallelConfig
 from repro.launch import mesh as mesh_lib
 from repro.models.unet import UNetConfig, UNetModel
@@ -40,7 +40,7 @@ print("UNET HETERO OK portals={portals}")
 
 AMOEBA = """
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs.base import ParallelConfig
 from repro.launch import mesh as mesh_lib
 from repro.models.amoebanet import AmoebaConfig, AmoebaNetModel

@@ -50,7 +50,7 @@ def assert_bitwise(ga, gb, tag):
 
 LM_ORACLE = COMMON + """
 from repro import configs
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs.base import ShapeConfig, ParallelConfig
 from repro.launch import mesh as mesh_lib
 from repro.models.lm import LMModel
@@ -179,7 +179,7 @@ print("LM ORACLE OK")
 
 LM_TRAIN_CURVE = COMMON + """
 from repro import configs
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs.base import ShapeConfig, ParallelConfig
 from repro.launch import mesh as mesh_lib, steps
 from repro.models.lm import LMModel
@@ -253,7 +253,7 @@ print("TRAIN CURVE OK")
 """
 
 UNET_ORACLE = COMMON + """
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs.base import ParallelConfig
 from repro.core import stage as stage_lib
 from repro.launch import mesh as mesh_lib

@@ -12,7 +12,7 @@ from conftest import run_subprocess
 EQUIV_TEMPLATE = """
 import zlib, dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro import configs
 from repro.configs.base import ShapeConfig, ParallelConfig
 from repro.launch import mesh as mesh_lib
@@ -93,7 +93,7 @@ def test_pipeline_equals_sequential(name, remat, portals, overlap):
 
 TRAIN_LOOP = """
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro import configs
 from repro.configs.base import ShapeConfig, ParallelConfig
 from repro.launch import mesh as mesh_lib, steps, sharding

@@ -234,7 +234,7 @@ def test_auto_plan_trains_bitwise_like_hand_config():
     from conftest import run_subprocess
     run_subprocess("""
         import jax, jax.numpy as jnp
-        from repro.compat import set_mesh
+        from jax import set_mesh
         from repro import configs
         from repro.configs.base import ParallelConfig, PlanSpec, ShapeConfig
         from repro.data.pipeline import DataConfig, SyntheticLM
@@ -290,7 +290,7 @@ def test_balanced_partition_trains_close_to_uniform():
     from conftest import run_subprocess
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import set_mesh
+        from jax import set_mesh
         from repro import configs
         from repro.configs.base import ParallelConfig, ShapeConfig
         from repro.data.pipeline import DataConfig, SyntheticLM

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.roofline import analysis as A
 
 
@@ -29,7 +28,7 @@ def test_trip_count_correction_on_scan():
     x = jax.ShapeDtypeStruct((32, D), jnp.float32)
     ws = jax.ShapeDtypeStruct((T, D, D), jnp.float32)
     compiled = jax.jit(scanned).lower(x, ws).compile()
-    xla_flops = compat.cost_analysis(compiled)["flops"]
+    xla_flops = compiled.cost_analysis()["flops"]
     cost = A.analyze_hlo(compiled.as_text(), 1)
     expect_dot = 2 * 32 * D * D * T
     # XLA undercounts by ~T; ours is within 1% of analytic

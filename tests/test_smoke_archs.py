@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro import configs
 from repro.configs.base import ShapeConfig
 from repro.launch import mesh as mesh_lib, steps

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro import configs
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs.base import ShapeConfig
@@ -92,7 +92,7 @@ def test_elastic_restack_preserves_function():
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
 from repro.configs.base import ShapeConfig
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch import mesh as mesh_lib
 from repro.models.lm import LMModel
@@ -139,3 +139,37 @@ l2 = loss_with(new_layout.with_(n_micro=2), dict(params, stages=restacked))
 np.testing.assert_allclose(l1, l2, rtol=2e-5)
 print("ELASTIC OK", l1, l2)
 """, n_devices=8, timeout=600)
+
+
+CACHE_PROBE = """
+import os
+if {env!r}:
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = {env!r}
+else:
+    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+import jax, jax.numpy as jnp
+from repro.launch.cache import enable_compile_cache
+path = enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+x = jnp.ones(3)
+before = set(os.listdir(path)) if os.path.isdir(path) else set()
+# a compile no earlier run can have cached: the constant is new
+jax.jit(lambda x: jnp.sin(x) * len({salt!r}) + hash({salt!r}) % 97)(x)
+print("CACHE", path, bool(set(os.listdir(path)) - before))
+"""
+
+
+@pytest.mark.parametrize("where", ["env", "checkout"])
+def test_compile_cache_location(tmp_path, where):
+    """Entry points cache compiles in $JAX_COMPILATION_CACHE_DIR when set,
+    else in the checkout's fixed, git-ignored ``.jax_cache/``."""
+    import os
+    from conftest import run_subprocess
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    env = str(tmp_path / "cc") if where == "env" else ""
+    out = run_subprocess(CACHE_PROBE.format(env=env, salt=str(tmp_path)),
+                         n_devices=1, timeout=300)
+    want = env or os.path.join(root, ".jax_cache")
+    assert f"CACHE {want} True" in out, out
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

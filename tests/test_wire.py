@@ -307,7 +307,7 @@ WIRE_PARITY = """
 import zlib
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs.base import ShapeConfig, ParallelConfig
 from repro.launch import mesh as mesh_lib
 from repro.models.lm import LMModel
@@ -381,7 +381,7 @@ print("WIRE PARITY OK")
 INT8_ORACLE = """
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs.base import ShapeConfig, ParallelConfig
 from repro.launch import mesh as mesh_lib, steps
 from repro.models.lm import LMModel
