@@ -74,6 +74,8 @@ from repro.core import plan as plan_lib
 from repro.core.plan import BWD, BWD_W, BWD_X, FWD, NOP, pipe_ring_perm
 from repro.core.skip import SkipSpec
 from repro.runtime.compression import _dequantize_block, _quantize_block
+from repro.scopes import (GRAD_REDUCE, PIPE, PIPE_B, PIPE_BW, PIPE_BX, PIPE_F,
+                           PIPE_HOP, scoped)
 
 PIPE_AXIS = "pipe"
 
@@ -100,6 +102,7 @@ def _select(pred, a, b):
     return jax.tree.map(lambda x, y: jnp.where(pred, x, y), a, b)
 
 
+@scoped(PIPE_HOP)
 def _shift_chain(value, n: int, axis: str, *, ring: bool = False):
     """Main pipeline hop: rank j -> j+1.  ``ring`` adds the wraparound pair
     (n-1 -> 0) that interleaved chunk boundaries ride; without it rank 0
@@ -111,6 +114,7 @@ def _shift_chain(value, n: int, axis: str, *, ring: bool = False):
     return jax.tree.map(lambda v: jax.lax.ppermute(v, axis, perm), value)
 
 
+@scoped(PIPE_HOP)
 def _shift_chain_rev(value, n: int, axis: str, *, ring: bool = False):
     """Backward (cotangent) hop: rank j -> j-1 (+ wraparound 0 -> n-1)."""
     if n == 1:
@@ -119,6 +123,7 @@ def _shift_chain_rev(value, n: int, axis: str, *, ring: bool = False):
     return jax.tree.map(lambda v: jax.lax.ppermute(v, axis, perm), value)
 
 
+@scoped(PIPE_HOP)
 def _route_hop(value, perm, axis: str):
     """One skip-route hop: a static (src, dst) pair list ppermute.  An empty
     perm means src and dst share a rank — the hop is an identity hold."""
@@ -973,6 +978,7 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
                 def nop_branch():
                     return out_zeros()
 
+                @scoped(PIPE_F)
                 def f_branch():
                     carry_out, skip_vals, loss_i, res_new = apply_full(
                         stage_params, x_f, skips_in, fresh_f, head_params)
@@ -981,6 +987,7 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
                              res=res_new)
                     return o
 
+                @scoped(PIPE_B)
                 def b_branch():
                     def f(p, c, si, fr, ph):
                         carry_out, skip_vals, loss_i, _ = apply_full(
@@ -998,6 +1005,7 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
                              g_ph=g_ph)
                     return o
 
+                @scoped(PIPE_BX)
                 def bx_branch():
                     def f(c, si, fr):
                         carry_out, skip_vals, loss_i, _ = apply_full(
@@ -1011,6 +1019,7 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
                     o.update(b=g_c, gskips=g_si, g_fr=g_fr)
                     return o
 
+                @scoped(PIPE_BW)
                 def bw_branch():
                     def f(p, ph):
                         carry_out, skip_vals, loss_i, _ = apply_full(
@@ -1040,6 +1049,7 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
                     rederivable = (resident, largs, micro_t, chunk_t, t,
                                    is_last_stage, idx)
 
+                    @scoped(PIPE_BX)
                     def bx_branch():
                         f = make_full_f(micro_t, chunk_t, t, is_last_stage,
                                         resident, largs)
@@ -1055,6 +1065,7 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
                                           if keep]
                         return o
 
+                    @scoped(PIPE_BW)
                     def bw_branch():
                         f = make_full_f(micro_t, chunk_t, t, is_last_stage,
                                         resident, largs)
@@ -1385,22 +1396,25 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
             # plan-flagged ticks (keeps rotation count == injected micros)
             if need_rot:
                 rot = [(i, (i - 1) % R) for i in range(R)]
-                spun = jax.tree.map(
-                    lambda a: jax.lax.ppermute(a, axis, rot), st["stream"])
+                with jax.named_scope(PIPE_HOP):
+                    spun = jax.tree.map(
+                        lambda a: jax.lax.ppermute(a, axis, rot),
+                        st["stream"])
                 out["stream"] = _select(xt["rot"], spun, st["stream"])
             return out, None
 
         return xs, tick_body
 
     state = st
-    for seg in tplan.segments:
-        xs, body = make_segment(seg)
-        if cfg.unroll_ticks:
-            for t in range(seg.stop - seg.start):
-                state, _ = body(state, jax.tree.map(lambda a, _t=t: a[_t],
-                                                    xs))
-        else:
-            state, _ = jax.lax.scan(body, state, xs)
+    with jax.named_scope(PIPE):
+        for seg in tplan.segments:
+            xs, body = make_segment(seg)
+            if cfg.unroll_ticks:
+                for t in range(seg.stop - seg.start):
+                    state, _ = body(state, jax.tree.map(
+                        lambda a, _t=t: a[_t], xs))
+            else:
+                state, _ = jax.lax.scan(body, state, xs)
 
     if not fb:
         return state["outputs"], state["resident"]
@@ -1409,8 +1423,9 @@ def run_pipeline_tasks(stage_apply: StageApplyFn,
     if ordered:
         # fixed-order reduction over the micro axis: the sum is identical
         # for every schedule, making gradients schedule-bitwise-stable.
-        g_stage = jax.tree.map(lambda a: jnp.sum(a, axis=0), g_stage)
-        g_head = jax.tree.map(lambda a: jnp.sum(a, axis=0), g_head)
+        with jax.named_scope(GRAD_REDUCE):
+            g_stage = jax.tree.map(lambda a: jnp.sum(a, axis=0), g_stage)
+            g_head = jax.tree.map(lambda a: jnp.sum(a, axis=0), g_head)
     return loss_acc, g_stage, g_head, igbuf, state["resident"]
 
 

@@ -23,6 +23,7 @@ from repro.launch import sharding
 from repro.models.lm import LMModel
 from repro.optim import optimizers as optim
 from repro.runtime.compression import EFCompressor
+from repro.scopes import GRAD_REDUCE, scoped
 
 
 def _carry_proto(model: LMModel, mbg: int, seq: int):
@@ -30,6 +31,7 @@ def _carry_proto(model: LMModel, mbg: int, seq: int):
                                       model.dtype)}
 
 
+@scoped(GRAD_REDUCE)
 def _maybe_compress_grads(pcfg: ParallelConfig, grads, opt_state):
     """int8-EF the DP gradient reduce (grad_compression="int8_ef").
 

@@ -24,6 +24,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import set_mesh
+from jax.profiler import TraceAnnotation as span
 import numpy as np
 
 from repro import configs
@@ -149,16 +150,25 @@ class ElasticTrainer:
 
     # -------------------------------------------------------------- step
     def step(self, state, i: int):
-        batch = {k: jnp.asarray(v) for k, v in self.data.batch_at(i).items()}
-        if self.injector is not None:
-            batch = self.injector.maybe_poison(i, batch)
-        with set_mesh(self.mesh):
-            p, o, m = self.jit_step(state["params"], state["opt"], batch)
-        metrics = {"loss": float(m["loss"]),
-                   "grad_norm": float(m["grad_norm"])}
-        if "skipped" in m:
-            metrics["skipped"] = int(m["skipped"])
-            metrics["finite"] = float(m["finite"])
+        """One training step.  Its host phases are profiler spans
+        (``train.step`` around ``train.batch``, ``train.put``,
+        ``train.dispatch`` and ``train.readback``), seen in any
+        ``jax.profiler`` trace of the run."""
+        with span("train.step"):
+            with span("train.batch"):
+                batch = self.data.batch_at(i)
+                if self.injector is not None:
+                    batch = self.injector.maybe_poison(i, batch)
+            with span("train.put"):
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            with span("train.dispatch"), set_mesh(self.mesh):
+                p, o, m = self.jit_step(state["params"], state["opt"], batch)
+            with span("train.readback"):
+                metrics = {"loss": float(m["loss"]),
+                           "grad_norm": float(m["grad_norm"])}
+                if "skipped" in m:
+                    metrics["skipped"] = int(m["skipped"])
+                    metrics["finite"] = float(m["finite"])
         return {"params": p, "opt": o}, metrics
 
     # --------------------------------------------------- straggler model

@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig, AttentionConfig, MoEConfig, SSMConfig
 from repro.core.pipeline import layout_hints_enabled
 from repro.kernels import ops
+from repro.scopes import ATTN, MLP, NORM, scoped
 
 BATCH = ("pod", "data")
 TP = "tp"
@@ -78,6 +79,7 @@ def norm_init(d: int, kind: str, dtype):
     return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
 
 
+@scoped(NORM)
 def norm_apply(p, x, kind: str, eps: float = 1e-6):
     if kind == "rms":
         return ops.rmsnorm(x, p["scale"], eps)
@@ -133,6 +135,7 @@ def _qkv(p, x, kv_src, a: AttentionConfig):
     return q, k, v
 
 
+@scoped(ATTN)
 def attn_apply(p, x, a: AttentionConfig, *, memory=None, window=None,
                causal=None, pos=None, kv_len=None):
     """Full-sequence attention (train / prefill).
@@ -227,6 +230,7 @@ def mlp_init(key, d: int, f: int, act: str, dtype, *, out_scale=1.0):
             "wd": dense_init(ks[1], f, d, dtype, out_scale)}
 
 
+@scoped(MLP)
 def mlp_apply(p, x, act: str):
     if act in ("silu", "geglu"):
         g = ffn_tp(x @ p["wg"])

@@ -28,6 +28,7 @@ from repro.core import stage as stage_lib
 from repro.core.skip import SkipSpec
 from repro.models import blocks as B
 from repro.models import layers as L
+from repro.scopes import EMBED, HEAD_LOSS, STAGE, scoped
 
 
 def _embed_lookup(table, tokens, dtype):
@@ -160,6 +161,7 @@ class LMModel:
         return {"mem": proto, "dec_in": proto}
 
     # ------------------------------------------------------------------ embed
+    @scoped(EMBED)
     def embed_inputs(self, emb, batch) -> Dict[str, jnp.ndarray]:
         """batch -> fresh stage-0 input pytree [B, ...]."""
         a = self.arch
@@ -248,19 +250,21 @@ class LMModel:
                 out = (h2, mem, dec_emb)
                 return out, (cache if prefill else None)
 
-            if prefill:
-                cache_mb = jax.tree.map(
-                    lambda x: jax.lax.dynamic_index_in_dim(
-                        x, ctx.micro, 1, keepdims=False), resident)
-                (h, mem, _), caches_new = jax.lax.scan(
-                    body, (h, mem, dec_emb), (stage_params, c_local, cache_mb))
-                resident = jax.tree.map(
-                    lambda full, new: jax.lax.dynamic_update_index_in_dim(
-                        full, new.astype(full.dtype), ctx.micro, 1),
-                    resident, caches_new)
-            else:
-                (h, mem, _), _ = jax.lax.scan(
-                    body, (h, mem, dec_emb), (stage_params, c_local))
+            with jax.named_scope(STAGE):
+                if prefill:
+                    cache_mb = jax.tree.map(
+                        lambda x: jax.lax.dynamic_index_in_dim(
+                            x, ctx.micro, 1, keepdims=False), resident)
+                    (h, mem, _), caches_new = jax.lax.scan(
+                        body, (h, mem, dec_emb),
+                        (stage_params, c_local, cache_mb))
+                    resident = jax.tree.map(
+                        lambda full, new: jax.lax.dynamic_update_index_in_dim(
+                            full, new.astype(full.dtype), ctx.micro, 1),
+                        resident, caches_new)
+                else:
+                    (h, mem, _), _ = jax.lax.scan(
+                        body, (h, mem, dec_emb), (stage_params, c_local))
 
             skips_out = {}
             if a.is_encdec:
@@ -327,6 +331,7 @@ class LMModel:
             w = emb.T
         return hn @ w
 
+    @scoped(HEAD_LOSS)
     def head_loss(self, params, h, labels, *, chunk: int = 0):
         """Chunked softmax cross-entropy over the sequence (never
         materializes [B, S, V] for the full sequence).

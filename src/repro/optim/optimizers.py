@@ -16,6 +16,8 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.scopes import OPTIMIZER, scoped
+
 
 class OptState(NamedTuple):
     step: jnp.ndarray
@@ -115,6 +117,7 @@ def _all_finite(grads, loss=None) -> jnp.ndarray:
                             jnp.asarray(True)) if flags else jnp.asarray(True)
 
 
+@scoped(OPTIMIZER)
 def apply(cfg: OptimizerConfig, state: OptState, params, grads,
           *, loss=None) -> Tuple[Any, OptState, dict]:
     """One optimizer step. Returns (new_params, new_state, metrics).

@@ -255,8 +255,9 @@ class Supervisor:
             self.watchdog.restarted()
 
     def _save(self, step: int, state):
-        extra = self.meta_fn(step) if self.meta_fn is not None else None
-        self.ckpt.save(step, self.state_for_ckpt(state), extra=extra)
+        with jax.profiler.TraceAnnotation("train.ckpt_save"):
+            extra = self.meta_fn(step) if self.meta_fn is not None else None
+            self.ckpt.save(step, self.state_for_ckpt(state), extra=extra)
 
     def _restore_or_init(self):
         step = self.ckpt.latest_step()
