@@ -11,12 +11,15 @@ Tests that force the Pallas path off the TPU (monkeypatching
 :func:`_use_pallas`) get the kernels in interpret mode; a TPU backend never
 interprets.
 
-The Pallas forwards are wrapped in ``jax.custom_vjp`` with backward passes
-taken from the reference implementations' VJPs: the recurrences are linear
-enough that XLA's fused backward of the blocked reference is already
-MXU-shaped, and it keeps the oracle and the gradient definition identical.
-(A hand-written dq/dk/dv Pallas backward is a further optimization hook; see
-EXPERIMENTS.md §Perf.)
+The Pallas forwards are wrapped in ``jax.custom_vjp``.  Attention's
+backward is Pallas too (:mod:`repro.kernels.attention_bwd`: lse and delta in
+one kernel, dq/dk/dv in another, K/V never repeated over a GQA group), from
+the residuals q, k, v alone.  It covers self-attention (``Sq == Sk``, no
+``q_offset``) whose query group's dq fits its VMEM block
+(``attention_bwd.supported``); anything else, chunked prefill among it (never
+differentiated), takes the VJP of :func:`repro.kernels.ref.mha_blocked`,
+which recomputes the forward in XLA.  The WKV-6 and RMSNorm backwards are
+their references' VJPs.
 """
 from __future__ import annotations
 
@@ -26,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels import ref
+from repro.kernels import attention_bwd, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.rwkv6 import wkv6_pallas
@@ -109,6 +112,10 @@ def _attention_fwd(q, k, v, causal, window, q_offset):
 
 def _attention_bwd(causal, window, q_offset, res, g):
     q, k, v = res
+    if q_offset == 0 and attention_bwd.supported(q.shape, k.shape):
+        fn = functools.partial(attention_bwd.attention_bwd, causal=causal,
+                               window=window, interpret=_interpret())
+        return _per_device(fn, (q, k, v, g), [(0, 1)] * 4, [(0, 1)] * 3)
     _, vjp = jax.vjp(
         lambda q_, k_, v_: ref.mha_blocked(q_, k_, v_, causal=causal,
                                            window=window, q_offset=q_offset),

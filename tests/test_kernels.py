@@ -132,6 +132,86 @@ def test_blocked_attention_traced_mask_params():
 
 
 # ---------------------------------------------------------------------------
+# Pallas attention backward (ops.attention's VJP on the Pallas path)
+# ---------------------------------------------------------------------------
+
+ATTN_BWD_CASES = [
+    # B, Hq, Hkv, S, D, causal, window, dtype, block
+    (1, 2, 2, 64, 64, True, 0, jnp.float32, None),
+    (2, 3, 1, 96, 64, True, 0, jnp.bfloat16, 32),       # GQA 3, many blocks
+    (1, 6, 2, 100, 64, True, 0, jnp.float32, 32),       # S needs padding
+    (1, 3, 3, 128, 128, False, 0, jnp.float32, 64),
+    (1, 3, 1, 150, 128, False, 0, jnp.bfloat16, None),  # one padded block
+    (1, 2, 2, 256, 64, True, 40, jnp.float32, 32),      # window skips blocks
+    (1, 3, 1, 120, 64, True, 24, jnp.bfloat16, 32),
+    (1, 6, 2, 96, 128, False, 48, jnp.float32, 32),
+]
+
+
+def _grads(attend, q, k, v, g):
+    def loss(q_, k_, v_):
+        return jnp.sum(attend(q_, k_, v_).astype(jnp.float32) * g)
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("case", ATTN_BWD_CASES)
+def test_pallas_attention_backward_vs_reference(case, monkeypatch):
+    """The Pallas backward behind ``ops.attention`` (interpret mode off the
+    TPU) against the VJPs of the naive and the blocked reference.  Its
+    products take bf16 operands, so it is held to bf16's precision: a
+    hidden score let through, or a visible one dropped, is off by O(1)."""
+    from repro.kernels import attention_bwd, ops
+    B, Hq, Hkv, S, D, causal, window, dtype, block = case
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    if block:
+        monkeypatch.setattr(attention_bwd, "BLOCK", block)
+    ks = jax.random.split(jax.random.PRNGKey(S + D), 4)
+    q = rand(ks[0], (B, Hq, S, D), dtype)
+    k = rand(ks[1], (B, Hkv, S, D), dtype)
+    v = rand(ks[2], (B, Hkv, S, D), dtype)
+    g = rand(ks[3], (B, Hq, S, D))
+    got = _grads(lambda *a: ops.attention(*a, causal=causal, window=window),
+                 q, k, v, g)
+    for oracle in (ref.mha_naive, ref.mha_blocked):
+        want = _grads(lambda *a: oracle(*a, causal=causal, window=window),
+                      q, k, v, g)
+        for a, b in zip(got, want):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert a.shape == b.shape
+            np.testing.assert_allclose(a, b, rtol=0, atol=2e-2 * np.abs(b).max())
+
+
+@pytest.mark.parametrize("case", ["q_offset", "dq_over_vmem"])
+def test_attention_fallback_takes_the_reference_vjp(case, monkeypatch):
+    """Shapes the Pallas backward does not take keep the blocked
+    reference's VJP, and the Pallas backward is never called: a query chunk
+    at ``q_offset`` (chunked prefill), and a query group whose dq would
+    not fit its VMEM block."""
+    from repro.kernels import attention_bwd, ops
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+
+    def refuse(*a, **kw):
+        raise AssertionError(f"the Pallas backward ran ({case})")
+    monkeypatch.setattr(attention_bwd, "attention_bwd", refuse)
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    if case == "q_offset":
+        sq, offset = 32, 64
+    else:
+        sq, offset = 96, 0
+        monkeypatch.setattr(attention_bwd, "DQ_VMEM", 96 * 32 * 4 - 1)
+    q = rand(ks[0], (1, 2, sq, 32))
+    k, v = rand(ks[1], (1, 2, 96, 32)), rand(ks[2], (1, 2, 96, 32))
+    g = rand(ks[3], (1, 2, sq, 32))
+    got = _grads(lambda *a: ops.attention(*a, causal=True, q_offset=offset),
+                 q, k, v, g)
+    want = _grads(lambda *a: ref.mha_blocked(*a, causal=True, q_offset=offset),
+                  q, k, v, g)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
 # RWKV-6 WKV kernel
 # ---------------------------------------------------------------------------
 

@@ -7,6 +7,7 @@ differentiation rule.  The topology is described inside a fixture, never at
 import, so only the test worker that runs this file loads the TPU compiler.
 """
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +16,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 from repro.kernels.rwkv6 import wkv6_pallas
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+from bench import trace as trace_lib  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -47,19 +53,34 @@ def _compile(fn, *shapes, sharding):
     return jax.jit(fn).lower(*args).compile()
 
 
-def test_flash_attention_fwd_and_vjp_compile(one_chip, pallas_path):
-    """smollm-360m attention: 15 query / 5 kv heads, S=2048, D=64."""
+@pytest.mark.parametrize("hq,hkv,d", [(15, 5, 64), (32, 32, 128)],
+                         ids=["smollm-360m", "deepseek-7b"])
+def test_flash_attention_fwd_and_vjp_compile(one_chip, pallas_path, hq, hkv, d):
+    """Attention at S=2048: smollm-360m's 15 query / 5 kv heads of 64, and
+    deepseek-7b's 32 heads of 128.  The grad holds the Pallas backward
+    kernels, and the benchmark's ``flash_attention`` finds only the forward:
+    one array out, q/k/v as operands 0-2 (``flash_attn_fwd_roofline``)."""
     def loss(q, k, v):   # squared, so the backward needs the forward's output
         o = ops.attention(q, k, v, causal=True).astype(jnp.float32)
         return (o * o).sum()
 
-    q, kv = (1, 15, 2048, 64), (1, 5, 2048, 64)
+    q, kv = (1, hq, 2048, d), (1, hkv, 2048, d)
     fwd = _compile(lambda q, k, v: ops.attention(q, k, v, causal=True),
                    q, kv, kv, sharding=one_chip)
     assert "tpu_custom_call" in fwd.as_text()
     bwd = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
                    sharding=one_chip)
-    assert "tpu_custom_call" in bwd.as_text()
+    kernels = trace_lib.pallas_kernels(bwd.as_text())
+    names = {n: k.functions for n, k in kernels.items()}
+    for kernel in ("attention_bwd_stats", "attention_bwd_grads"):
+        assert [n for n, f in names.items() if kernel in f], names
+    flash = [k for k in kernels.values() if "flash_attention" in k.functions]
+    assert flash, names
+    for k in flash:
+        assert k.result is not None and k.result.shape == (hq, 2048, d)
+        assert [a.shape for a in k.operands[:3]] == [
+            (hq, 2048, d), (hkv, 2048, d), (hkv, 2048, d)]
+        assert not {"attention_bwd_stats", "attention_bwd_grads"} & set(k.functions)
 
 
 def test_rmsnorm_fwd_and_grad_compile(one_chip, pallas_path):
