@@ -49,6 +49,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import Tiling, block_size, clamp, vmem_limit
+
 NEG_INF = -1e30
 BLOCK = 1024            # query / key rows of one grid step
 DQ_VMEM = 16 << 20      # bytes of one query group's f32 dq, held in VMEM
@@ -59,78 +61,6 @@ _NN = (((1,), (0,)), ((), ()))      # a @ b
 def _dot(a, b, dims):
     return lax.dot_general(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
                            dims, preferred_element_type=jnp.float32)
-
-
-class _Tiling:
-    """Which (q block, k block) pairs hold visible scores, for static
-    ``causal``/``window`` over ``sk`` keys, padded to ``n`` blocks of ``b``
-    on both sides."""
-
-    def __init__(self, *, causal, window, sk, b, n):
-        self.causal, self.window, self.sk, self.b, self.n = (
-            causal, window, sk, b, n)
-
-    def k_range(self, qi):
-        """First and last k block that q block ``qi`` sees."""
-        lo, hi = 0, self.n - 1
-        if self.window > 0:       # a negative first key truncates to 0
-            lo = lax.max(lax.div(lax.sub(lax.mul(qi, self.b), self.window - 1),
-                                 self.b), 0)
-        if self.causal:
-            hi = qi
-        return lo, hi
-
-    def q_range(self, ki):
-        """First and last q block that sees k block ``ki``."""
-        lo, hi = 0, self.n - 1
-        if self.causal:
-            lo = ki
-        if self.window > 0:
-            last = lax.add(lax.mul(ki, self.b), self.b + self.window - 2)
-            hi = lax.min(lax.div(last, self.b), hi)
-        return lo, hi
-
-    def full(self, qi, ki):
-        """Every score of the pair is visible: no mask to build."""
-        k_last = lax.add(lax.mul(ki, self.b), self.b - 1)
-        out = lax.lt(k_last, self.sk)
-        if self.causal:
-            out = lax.bitwise_and(out, lax.lt(ki, qi))
-        if self.window > 0:
-            q_last = lax.add(lax.mul(qi, self.b), self.b - 1)
-            out = lax.bitwise_and(out, lax.gt(lax.mul(ki, self.b),
-                                              lax.sub(q_last, self.window)))
-        return out
-
-    def mask(self, st, qi, ki):
-        """``sᵀ`` of the pair with its hidden scores at -inf.  Key ``i`` of
-        the pair lies ``i - j - (qi - ki) b`` after its query ``j``."""
-        i = lax.broadcasted_iota(jnp.int32, st.shape, 0)
-        ahead = i - lax.broadcasted_iota(jnp.int32, st.shape, 1)
-        offset = lax.mul(lax.sub(qi, ki), self.b)
-        visible = None
-        if self.causal:                     # key <= query
-            visible = ahead <= offset
-        if self.window > 0:                 # key > query - window
-            behind = ahead > lax.sub(offset, self.window)
-            visible = behind if visible is None else visible & behind
-        if self.n * self.b > self.sk:       # keys padded to blocks
-            real = i < lax.sub(self.sk, lax.mul(ki, self.b))
-            visible = real if visible is None else visible & real
-        return st if visible is None else jnp.where(visible, st, -jnp.inf)
-
-    def when_needed(self, qi, ki, step):
-        """Run ``step(masked)`` on the pair, unless the mask hides it all."""
-        q_lo, q_hi = self.q_range(ki)
-        needed = lax.bitwise_and(lax.le(q_lo, qi), lax.le(qi, q_hi))
-        full = self.full(qi, ki)
-        pl.when(lax.bitwise_and(needed, full))(lambda: step(False))
-        pl.when(lax.bitwise_and(needed, lax.bitwise_not(full)))(
-            lambda: step(True))
-
-
-def _clamp(i, lo, hi):
-    return lax.min(lax.max(i, lo), hi)
 
 
 def _stats_kernel(q_ref, k_ref, vt_ref, do_ref, lse_ref, delta_ref,
@@ -157,9 +87,9 @@ def _stats_kernel(q_ref, k_ref, vt_ref, do_ref, lse_ref, delta_ref,
         m_ref[...] = m_new
         ot_ref[...] = alpha * ot_ref[...] + _dot(vt_ref[0], pt, _NN)
 
-    t.when_needed(qi, ki, step)
+    t.when_needed(t.sees(qi, ki, by_k=False), qi, ki, step)
 
-    @pl.when(lax.eq(ki, t.n - 1))
+    @pl.when(lax.eq(ki, t.nk - 1))
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
         lse_ref[0] = m_ref[...] + jnp.log(l)
@@ -172,7 +102,7 @@ def _grads_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
                   tiling, group):
     t = tiling
     ki, j = pl.program_id(1), pl.program_id(2)
-    qi = lax.rem(j, t.n)
+    qi = lax.rem(j, t.nq)
     last = lax.eq(j, pl.num_programs(2) - 1)
 
     @pl.when(lax.bitwise_and(lax.eq(ki, 0), lax.eq(j, 0)))
@@ -197,29 +127,25 @@ def _grads_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] += _dot(dst, q, _NN)
         dqt_acc[j] += _dot(kt_ref[0], dst, _NN)              # dqᵀ [D, queries]
 
-    t.when_needed(qi, ki, step)
+    t.when_needed(t.sees(qi, ki, by_k=False), qi, ki, step)
 
     @pl.when(last)
     def _finish_dkv():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
-    @pl.when(lax.bitwise_and(last, lax.eq(ki, t.n - 1)))
+    @pl.when(lax.bitwise_and(last, lax.eq(ki, t.nk - 1)))
     def _finish_dq():
         for h in range(group):
-            for i in range(t.n):
+            for i in range(t.nq):
                 dq_ref[0, h, pl.ds(i * t.b, t.b), :] = jnp.transpose(
-                    dqt_acc[h * t.n + i] * scale).astype(dq_ref.dtype)
-
-
-def _block(s: int) -> int:
-    """One block of the whole (8-aligned) sequence, or ``BLOCK``."""
-    return min(-(-s // 8) * 8, BLOCK)
+                    dqt_acc[h * t.nq + i] * scale).astype(dq_ref.dtype)
 
 
 def _padded(s: int) -> int:
     """The sequence padded to whole blocks."""
-    return -(-s // _block(s)) * _block(s)
+    b = block_size(s, BLOCK)
+    return -(-s // b) * b
 
 
 def supported(q_shape, k_shape) -> bool:
@@ -246,7 +172,7 @@ def attention_bwd(q, k, v, do, *, causal: bool = True, window: int = 0,
                          f"{DQ_VMEM} bytes only: q {q.shape}, k/v {v.shape}")
     group = Hq // Hkv
     scale = D ** -0.5
-    b, sp = _block(S), _padded(S)
+    b, sp = block_size(S, BLOCK), _padded(S)
     n = sp // b
     f32, bf16 = jnp.float32, jnp.bfloat16
 
@@ -254,14 +180,14 @@ def attention_bwd(q, k, v, do, *, causal: bool = True, window: int = 0,
         x = x.reshape(-1, S, x.shape[-1])
         return jnp.pad(x, ((0, 0), (0, sp - S), (0, 0))) if sp > S else x
     qf, kf, vf, dof = map(flat, (q, k, v, do))
-    tiling = _Tiling(causal=causal, window=window, sk=S, b=b, n=n)
-    vmem = _vmem_limit(b)
+    tiling = Tiling(causal=causal, window=window, sk=S, b=b, nq=n, nk=n)
+    vmem = vmem_limit(b)
 
     def kv_block(bh, qi, ki):
-        return (lax.div(bh, group), _clamp(ki, *tiling.k_range(qi)), 0)
+        return (lax.div(bh, group), clamp(ki, *tiling.k_range(qi)), 0)
 
     def vt_block(bh, qi, ki):
-        return (lax.div(bh, group), 0, _clamp(ki, *tiling.k_range(qi)))
+        return (lax.div(bh, group), 0, clamp(ki, *tiling.k_range(qi)))
     q_block = lambda bh, qi, ki: (bh, qi, 0)
     q_row = lambda bh, qi, ki: (bh, 0, qi)
     lse, delta = pl.pallas_call(
@@ -287,7 +213,7 @@ def attention_bwd(q, k, v, do, *, causal: bool = True, window: int = 0,
     )(qf, kf, jnp.swapaxes(vf, 1, 2), dof)
 
     def qi_of(ki, j):
-        return _clamp(lax.rem(j, n), *tiling.q_range(ki))
+        return clamp(lax.rem(j, n), *tiling.q_range(ki))
 
     def bh_of(bkv, j):
         return lax.add(lax.mul(bkv, group), lax.div(j, n))
@@ -328,8 +254,3 @@ def attention_bwd(q, k, v, do, *, causal: bool = True, window: int = 0,
         return x.reshape(B, h, sp, x.shape[-1])[:, :, :S]
     return unflat(dq, Hq), unflat(dk, Hkv), unflat(dv, Hkv)
 
-
-def _vmem_limit(b):
-    """Room for about six f32 score blocks of temporaries, and 16 MiB for
-    the double-buffered operand blocks and the accumulators."""
-    return 6 * b * b * 4 + (16 << 20)

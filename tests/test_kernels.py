@@ -38,7 +38,7 @@ def test_flash_attention_vs_oracle(shape, dtype, causal, window):
     k = rand(ks[1], (B, Hkv, Sk, D), dtype)
     v = rand(ks[2], (B, Hkv, Sk, D), dtype)
     got = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=32, block_k=32, interpret=True)
+                          block=32, interpret=True)
     want = ref.mha_naive(q, k, v, causal=causal, window=window)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -55,7 +55,7 @@ def test_flash_attention_q_offset_decodes_prefill_chunk():
     v = rand(ks[2], (B, H, S, D))
     full = ref.mha_naive(q, k, v, causal=True)
     half = flash_attention(q[:, :, 64:], k, v, causal=True, q_offset=64,
-                           block_q=32, block_k=32, interpret=True)
+                           block=32, interpret=True)
     np.testing.assert_allclose(np.asarray(half), np.asarray(full[:, :, 64:]),
                                rtol=2e-5, atol=2e-5)
 
@@ -68,11 +68,114 @@ def test_flash_attention_property(b, h, s, d):
     q = rand(ks[0], (b, h, s, d))
     k = rand(ks[1], (b, h, s, d))
     v = rand(ks[2], (b, h, s, d))
-    got = flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
-                          interpret=True)
+    got = flash_attention(q, k, v, causal=True, block=16, interpret=True)
     want = ref.mha_naive(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-5, atol=3e-5)
+
+
+FLASH_BF16_CASES = [
+    # B, Hq, Hkv, Sq, Sk, D, causal, window, q_offset, BLOCK
+    (2, 15, 5, 96, 96, 64, True, 0, 0, 32),     # SmolLM's GQA 3:1, 3 blocks
+    (1, 6, 2, 100, 100, 64, True, 0, 0, 32),    # S not a multiple of BLOCK
+    (1, 3, 1, 160, 160, 64, True, 40, 0, 32),   # window skips blocks
+    (1, 3, 1, 64, 160, 64, True, 0, 96, 32),    # q_offset chunk, whole blocks
+    (1, 3, 1, 40, 120, 64, True, 24, 80, 16),   # chunk off the block grid
+    (1, 2, 2, 72, 72, 128, False, 0, 0, 32),    # non-causal, padded, D=128
+]
+
+
+@pytest.mark.parametrize("case", FLASH_BF16_CASES)
+def test_flash_attention_bf16_blocks_vs_oracle(case, monkeypatch):
+    """bf16 inputs through the default block rule (``BLOCK`` made small so
+    the sequence spans several blocks): single-pass products, held to the
+    naive oracle at bf16's precision."""
+    from repro.kernels import flash_attention as fa
+    B, Hq, Hkv, Sq, Sk, D, causal, window, q_offset, block = case
+    monkeypatch.setattr(fa, "BLOCK", block)
+    ks = jax.random.split(jax.random.PRNGKey(Sq + Sk + D), 3)
+    q = rand(ks[0], (B, Hq, Sq, D), jnp.bfloat16)
+    k = rand(ks[1], (B, Hkv, Sk, D), jnp.bfloat16)
+    v = rand(ks[2], (B, Hkv, Sk, D), jnp.bfloat16)
+    got = flash_attention(q, k, v, causal=causal, window=window,
+                          q_offset=q_offset, interpret=True)
+    want = ref.mha_naive(q, k, v, causal=causal, window=window,
+                         q_offset=q_offset)
+    assert got.dtype == jnp.bfloat16 and got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-2)
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` of a jaxpr, those of nested jaxprs included."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == "dot_general":
+            yield e
+        for p in e.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _dots(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_flash_attention_products_take_the_inputs_dtype(dtype):
+    """Both products of the kernel take their operands in the inputs' dtype
+    and accumulate in f32: single-pass MXU products for bf16 inputs, f32
+    products kept for f32 inputs."""
+    q = jnp.zeros((1, 3, 64, 64), dtype)
+    kv = jnp.zeros((1, 1, 64, 64), dtype)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, block=32, interpret=True))(q, kv, kv).jaxpr
+    dots = list(_dots(jaxpr))
+    assert dots
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [dtype, dtype]
+        assert e.params["preferred_element_type"] == jnp.float32
+
+
+TILING_CASES = [
+    # causal, window, q_offset, sq, sk, b
+    (True, 0, 0, 100, 100, 16),
+    (False, 0, 0, 40, 72, 16),
+    (True, 24, 0, 96, 96, 16),
+    (False, 40, 0, 64, 64, 16),
+    (True, 0, 48, 32, 80, 16),
+    (True, 20, 37, 45, 82, 16),
+    (True, 0, 0, 80, 40, 16),       # more queries than keys
+]
+
+
+@pytest.mark.parametrize("case", TILING_CASES)
+def test_tiling_matches_the_mask(case):
+    """Every (q block, k block) pair: ``sees`` where some score is visible,
+    ``full`` where all are, the clamped k range holds every visible pair,
+    and ``mask`` hides exactly the scores the oracle's mask hides."""
+    from repro.kernels.tiling import Tiling
+    causal, window, q_offset, sq, sk, b = case
+    nq, nk = -(-sq // b), -(-sk // b)
+    t = Tiling(causal=causal, window=window, sk=sk, b=b, nq=nq, nk=nk,
+               q_offset=q_offset)
+    want = np.zeros((nq * b, nk * b), bool)
+    want[:sq, :sk] = np.asarray(ref.attention_mask(
+        sq, sk, causal=causal, window=window, q_offset=q_offset))
+    ones = jnp.zeros((b, b), jnp.float32)
+    for qi in range(nq):
+        lo, hi = (int(x) for x in t.k_range(jnp.int32(qi)))
+        for ki in range(nk):
+            pair = want[qi * b:(qi + 1) * b, ki * b:(ki + 1) * b]
+            real = pair[:min(b, sq - qi * b)]      # padded queries: any
+            qi_, ki_ = jnp.int32(qi), jnp.int32(ki)
+            assert bool(t.sees(qi_, ki_)) == real.any() == (lo <= ki <= hi)
+            if real.any():
+                assert bool(t.full(qi_, ki_)) == real.all()
+                shown = np.isfinite(np.asarray(t.mask(ones, qi_, ki_))).T
+                np.testing.assert_array_equal(shown[:len(real)], real)
+            if t.nq == t.nk and not q_offset:
+                q_lo, q_hi = (int(x) for x in t.q_range(ki_))
+                assert (q_lo <= qi <= q_hi) == real.any()
 
 
 # ---------------------------------------------------------------------------

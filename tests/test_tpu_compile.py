@@ -53,18 +53,21 @@ def _compile(fn, *shapes, sharding):
     return jax.jit(fn).lower(*args).compile()
 
 
-@pytest.mark.parametrize("hq,hkv,d", [(15, 5, 64), (32, 32, 128)],
-                         ids=["smollm-360m", "deepseek-7b"])
-def test_flash_attention_fwd_and_vjp_compile(one_chip, pallas_path, hq, hkv, d):
-    """Attention at S=2048: smollm-360m's 15 query / 5 kv heads of 64, and
-    deepseek-7b's 32 heads of 128.  The grad holds the Pallas backward
+@pytest.mark.parametrize(
+    "hq,hkv,s,d", [(15, 5, 2048, 64), (32, 32, 2048, 128), (15, 5, 4096, 64)],
+    ids=["smollm-360m", "deepseek-7b", "smollm-360m-4k"])
+def test_flash_attention_fwd_and_vjp_compile(one_chip, pallas_path, hq, hkv,
+                                             s, d):
+    """Attention: smollm-360m's 15 query / 5 kv heads of 64 at S=2048 and
+    4096 (the benchmark's cells), and deepseek-7b's 32 heads of 128 at
+    S=2048.  The grad holds the Pallas backward
     kernels, and the benchmark's ``flash_attention`` finds only the forward:
     one array out, q/k/v as operands 0-2 (``flash_attn_fwd_roofline``)."""
     def loss(q, k, v):   # squared, so the backward needs the forward's output
         o = ops.attention(q, k, v, causal=True).astype(jnp.float32)
         return (o * o).sum()
 
-    q, kv = (1, hq, 2048, d), (1, hkv, 2048, d)
+    q, kv = (1, hq, s, d), (1, hkv, s, d)
     fwd = _compile(lambda q, k, v: ops.attention(q, k, v, causal=True),
                    q, kv, kv, sharding=one_chip)
     assert "tpu_custom_call" in fwd.as_text()
@@ -77,10 +80,36 @@ def test_flash_attention_fwd_and_vjp_compile(one_chip, pallas_path, hq, hkv, d):
     flash = [k for k in kernels.values() if "flash_attention" in k.functions]
     assert flash, names
     for k in flash:
-        assert k.result is not None and k.result.shape == (hq, 2048, d)
+        assert k.result is not None and k.result.shape == (hq, s, d)
         assert [a.shape for a in k.operands[:3]] == [
-            (hq, 2048, d), (hkv, 2048, d), (hkv, 2048, d)]
+            (hq, s, d), (hkv, s, d), (hkv, s, d)]
         assert not {"attention_bwd_stats", "attention_bwd_grads"} & set(k.functions)
+
+
+def test_flash_attention_is_found_in_a_rematerialised_layer_loop(
+        one_chip, pallas_path):
+    """The train step's form: attention in a ``lax.scan`` of
+    ``jax.checkpoint``-ed layers.  There the kernel body's operations can
+    lose their own source locations, so the forward is known by the name
+    of its ``pallas_call``: ``flash_attention``, on the forward kernel
+    alone (``flash_attn_fwd_roofline``)."""
+    def loss(q, k, v):
+        def layer(x, _):
+            return jax.checkpoint(
+                lambda x: ops.attention(x, k, v, causal=True))(x), None
+        o, _ = jax.lax.scan(layer, q, None, length=2)
+        o = o.astype(jnp.float32)
+        return (o * o).sum()
+
+    q, kv = (1, 15, 2048, 64), (1, 5, 2048, 64)
+    grad = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
+                    sharding=one_chip)
+    kernels = trace_lib.pallas_kernels(grad.as_text())
+    flash = [k for k in kernels.values() if "flash_attention" in k.functions]
+    assert flash, {n: k.functions for n, k in kernels.items()}
+    for k in flash:
+        assert k.result is not None and k.result.shape == (15, 2048, 64)
+        assert [a.shape for a in k.operands[:3]] == [q[1:], kv[1:], kv[1:]]
 
 
 def test_rmsnorm_fwd_and_grad_compile(one_chip, pallas_path):
